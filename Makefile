@@ -44,7 +44,7 @@ bench-smoke:
 	$(PYTHON) benchmarks/decode_throughput.py --smoke --out bench-artifacts/BENCH_decode_smoke.json
 	$(PYTHON) benchmarks/decode_throughput.py --smoke --cache-layout paged --out bench-artifacts/BENCH_paged_smoke.json
 	$(PYTHON) benchmarks/control_loop.py --smoke --out bench-artifacts/BENCH_control_smoke.json
-	$(PYTHON) -m repro.launch.serve --slots 1 --requests-per-slot 8 --gen-len 2 \
+	$(PYTHON) -m repro.launch.serve --reduced --slots 1 --requests-per-slot 8 --gen-len 2 \
 		--trace-out bench-artifacts/trace_smoke.json \
 		--stats-report bench-artifacts/serve_report_smoke.json
 	$(PYTHON) tools/check_trace.py bench-artifacts/trace_smoke.json

@@ -48,6 +48,7 @@ from repro.core.profiles import profile_from_arch
 from repro.core.thresholds import synthetic_validation
 from repro.core.topology import NetworkSpec, build_edge_network
 from repro.core.types import DtoHyperParams, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving import CollaborativeEngine
 
@@ -467,6 +468,7 @@ def main() -> None:
         help="tiny workload; validate schema + invariants, skip win gates",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.n_requests = 32

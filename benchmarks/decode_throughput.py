@@ -37,7 +37,9 @@ from repro.core.profiles import profile_from_arch
 from repro.core.thresholds import synthetic_validation
 from repro.core.topology import NetworkSpec, build_edge_network
 from repro.core.types import DtoHyperParams
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
+from repro.roofline.constants import PEAKS
 from repro.serving import CollaborativeEngine, monolithic_generate
 
 
@@ -172,6 +174,7 @@ def bench_roofline(
     prompt_len: int,
     batch_size: int,
     arrival_rate: float,
+    device_kind: str,
     serve_seed: int = 123,
     num_slots: int | None = None,
 ) -> dict:
@@ -180,7 +183,7 @@ def bench_roofline(
     The tracer accumulates real wall seconds around every jitted stage
     program (prefill and decode separately) plus the device work shipped;
     joining with the analytic per-stage FLOP/byte counts turns that into a
-    per-(stage, phase) utilization against the hardware bound."""
+    per-(stage, phase) utilization against ``device_kind``'s bound."""
     from repro.obs import SpanTracer, roofline_utilization
 
     rng = np.random.default_rng(0)
@@ -208,7 +211,7 @@ def bench_roofline(
         num_slots=num_slots,
         tracer=tracer,
     )
-    rows = roofline_utilization(tracer, eng.cfg)
+    rows = roofline_utilization(tracer, eng.cfg, device_kind)
     for key, r in rows.items():
         print(
             f"roofline {key:18s}: wall {r['measured_wall_s']*1e3:8.2f}ms  "
@@ -222,6 +225,7 @@ def bench_roofline(
             "gen_len": gen_len,
             "batch_size": batch_size,
         },
+        "device_kind": device_kind,
         "by_stage_phase": rows,
     }
 
@@ -404,6 +408,8 @@ def validate_schema(payload: dict) -> None:
             m = entry["by_mode"][mode]
             for field in ("wall_s", "tokens_per_s", "generated_tokens", "num_batches"):
                 assert np.isfinite(m[field]), f"{mode}.{field} not finite"
+    if "not_measured" in payload["roofline"]:
+        return
     roof = payload["roofline"]["by_stage_phase"]
     assert roof, "roofline join produced no (stage, phase) rows"
     phases = {r["phase"] for r in roof.values()}
@@ -454,6 +460,7 @@ def main() -> None:
         help="tiny workload; validate the JSON schema and exit nonzero on drift",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     meta = {
         "jax": jax.__version__,
         "backend": jax.default_backend(),
@@ -497,15 +504,23 @@ def main() -> None:
         repeats=args.repeats,
         num_slots=args.num_slots,
     )
-    roofline_res = bench_roofline(
-        eng,
-        gen_len=max(args.gen_lens),
-        n_requests=args.n_requests,
-        prompt_len=args.prompt_len,
-        batch_size=args.batch_size,
-        arrival_rate=args.arrival_rate,
-        num_slots=args.num_slots,
-    )
+    device_kind = jax.devices()[0].device_kind
+    if device_kind in PEAKS:
+        roofline_res = bench_roofline(
+            eng,
+            gen_len=max(args.gen_lens),
+            n_requests=args.n_requests,
+            prompt_len=args.prompt_len,
+            batch_size=args.batch_size,
+            arrival_rate=args.arrival_rate,
+            device_kind=device_kind,
+            num_slots=args.num_slots,
+        )
+    else:
+        roofline_res = {
+            "not_measured": f"no published peaks for device kind {device_kind!r}"
+        }
+        print(f"roofline: not measured ({roofline_res['not_measured']})")
     payload = {"decode": res, "roofline": roofline_res, "meta": meta}
     validate_schema(payload)
     with open(args.out, "w") as f:

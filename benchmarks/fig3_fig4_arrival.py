@@ -11,6 +11,7 @@ from benchmarks.common import ALGOS, decide, fmt_row, run_slot
 from repro.core.thresholds import synthetic_validation
 from repro.core.topology import build_edge_network
 from repro.core.types import BERT_PROFILE, DtoHyperParams, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 
 SCALES = {
     "resnet101": (2.0, 2.5, 3.0, 3.5),
@@ -64,4 +65,5 @@ def run(seed: int = 0, duration: float = 5.0) -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -8,6 +8,7 @@ from benchmarks.common import ALGOS, decide, fmt_row, run_slot
 from repro.core.thresholds import synthetic_validation
 from repro.core.topology import build_edge_network, with_capacity_scale
 from repro.core.types import BERT_PROFILE, DtoHyperParams, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 
 CAP_SCALES = (0.65, 1.0, 1.5)
 ARRIVAL = {"resnet101": 2.5, "bert": 0.65}
@@ -34,4 +35,5 @@ def run(seed: int = 0, duration: float = 5.0) -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

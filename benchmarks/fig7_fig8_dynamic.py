@@ -16,6 +16,7 @@ from repro.core.topology import (
     with_resampled_capacities,
 )
 from repro.core.types import BERT_PROFILE, DtoHyperParams, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 
 ARRIVAL = {"resnet101": 3.0, "bert": 0.7}
 
@@ -70,4 +71,5 @@ def run(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -11,6 +11,7 @@ from repro.core import dto_ee, simulator
 from repro.core.thresholds import synthetic_validation
 from repro.core.topology import build_uniform_network, with_arrival_rates
 from repro.core.types import DtoHyperParams, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 
 FIXED = (1.0, 0.9, 0.8, 0.7)
 
@@ -76,4 +77,5 @@ def run(seed: int = 0, slots: int = 10, duration: float = 5.0) -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -13,6 +13,7 @@ import os
 
 from repro.configs import SHAPES, get_config, list_archs
 from repro.configs.base import shape_applicable
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 
 ART = os.path.normpath(
@@ -81,4 +82,5 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -8,6 +8,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -16,6 +18,7 @@ def main() -> None:
         "--only", default="", help="comma list: table2,fig34,fig56,fig78,fig9,roofline"
     )
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def want(name: str) -> bool:

@@ -28,6 +28,7 @@ from repro.core.profiles import profile_from_arch
 from repro.core.thresholds import synthetic_validation
 from repro.core.topology import NetworkSpec, build_edge_network
 from repro.core.types import DtoHyperParams, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving import CollaborativeEngine
 
@@ -284,6 +285,7 @@ def main() -> None:
         help="Poisson arrival rate; high = closed-loop (all requests queued)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     eng = build_engine()
     engine_res = bench_engine(

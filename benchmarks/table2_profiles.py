@@ -9,6 +9,7 @@ from __future__ import annotations
 from repro.configs import get_config, list_archs
 from repro.core.profiles import profile_from_arch
 from repro.core.types import BERT_PROFILE, RESNET101_PROFILE
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run() -> list[str]:
@@ -30,4 +31,5 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -11,6 +11,13 @@ logits never leave VMEM.
   grid = (batch_blocks, vocab_blocks); vocab axis sequential, carrying
   (m, l, argmax) scratch.  conf = 1 / sum_v exp(logit_v - max) because the
   top-1 term contributes exp(0).
+
+The head block is streamed in the dtype it arrives in, so callers pass it in
+the activation dtype (``ops.exit_confidence`` casts): a bf16 [d, block_v]
+tile is half the VMEM of an f32 one, which is what lets block_v=1024 fit at
+d=2048.  Outputs are lane-dense [B, 128] tiles (every lane holds the row's
+value) so the batch axis tiles in multiples of 8 rows for any B; the wrapper
+returns lane 0.
 """
 from __future__ import annotations
 
@@ -21,16 +28,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _exit_kernel(
     h_ref,  # [block_b, d]
     w_ref,  # [d, block_v]
-    conf_ref,  # [block_b]
-    idx_ref,  # [block_b]
+    conf_ref,  # [block_b, 128] (lane-broadcast)
+    idx_ref,  # [block_b, 128] (lane-broadcast)
     m_scr,  # [block_b, 128] f32 running max
     l_scr,  # [block_b, 128] f32 running sum-exp
     a_scr,  # [block_b, 128] i32 running argmax
@@ -75,9 +80,9 @@ def _exit_kernel(
 
     @pl.when(iv == num_v_blocks - 1)
     def _emit():
-        l = l_scr[:, 0]
+        l = l_scr[...]
         conf_ref[...] = 1.0 / jnp.where(l > 0.0, l, 1.0)
-        idx_ref[...] = a_scr[:, 0]
+        idx_ref[...] = a_scr[...]
 
 
 @functools.partial(
@@ -94,6 +99,8 @@ def exit_confidence(
     """Returns (top1 softmax prob [B] f32, argmax [B] i32)."""
     B, d = h.shape
     V = w.shape[1]
+    # one batch block when B fits (a block equal to the full dim always
+    # tiles); otherwise block_b rows, a multiple of 8 sublanes on TPU
     block_b = min(block_b, B)
     block_v = min(block_v, V)
     b_pad = (-B) % block_b
@@ -116,22 +123,22 @@ def exit_confidence(
             pl.BlockSpec((d, block_v), lambda ib, iv: (0, iv)),
         ],
         out_specs=[
-            pl.BlockSpec((block_b,), lambda ib, iv: (ib,)),
-            pl.BlockSpec((block_b,), lambda ib, iv: (ib,)),
+            pl.BlockSpec((block_b, 128), lambda ib, iv: (ib, 0)),
+            pl.BlockSpec((block_b, 128), lambda ib, iv: (ib, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B + b_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((B + b_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((B + b_pad, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B + b_pad, 128), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, 128), jnp.float32),
             pltpu.VMEM((block_b, 128), jnp.float32),
             pltpu.VMEM((block_b, 128), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="exit_confidence",
     )(h, w)
-    return conf[:B], idx[:B]
+    return conf[:B, 0], idx[:B, 0]

@@ -120,12 +120,15 @@ def exit_confidence(
     block_b: int = 128,
     block_v: int = 1024,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(top-1 softmax prob, argmax) of ``h @ w`` with the head ``w`` cast to
+    the activation dtype, as the oracle casts it: the serving path holds f32
+    master weights, and a bf16 head tile is what fits the kernel's VMEM."""
     be = get_backend()
     if be == "xla":
         return ref.exit_confidence_ref(h, w)
     return _exit.exit_confidence(
         h,
-        w,
+        w.astype(h.dtype),
         block_b=block_b,
         block_v=block_v,
         interpret=(be == "pallas_interpret"),

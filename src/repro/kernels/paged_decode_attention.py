@@ -8,14 +8,18 @@ operand (SMEM before the body runs), so the k/v ``BlockSpec`` index maps can
 dereference it: grid step ``(b, h, j)`` DMAs physical block ``table[b, j]``
 straight from the pool — the virtual sequence is never materialized in HBM.
 
-  * grid = (batch, kv_heads, n_logical); last axis sequential, carrying the
-    (m, l, acc) scratch across the row's block walk.
+  * grid = (batch, head_blocks, n_logical); last axis sequential, carrying
+    the (m, l, acc) scratch across the row's block walk.
+  * the pool is viewed as ``[num_blocks, block_size, KVH * hd]`` and read in
+    the dense kernel's lane blocks of ``hp`` heads against block-diagonally
+    packed queries (see ``decode_attention`` for why a one-head block does
+    not tile on TPU).
   * unallocated logical blocks point at the pool's trash row; their
     positions are ``>= lengths[b]`` so the whole tile is skipped (masked and
     ``pl.when``-gated, same as padded tail blocks in the dense kernel).
-  * one pool block per grid step: ``block_size`` should be a multiple of
-    the lane tiling (128) for peak DMA efficiency on real TPUs; tiny blocks
-    work but stream narrow tiles.
+  * one pool block per grid step: ``block_size`` rows are the tile's
+    second-minor dim, which equals the pool's, so any block size tiles;
+    larger blocks stream fewer, longer DMAs.
 """
 from __future__ import annotations
 
@@ -27,21 +31,26 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (
+    attend_tile,
+    emit,
+    heads_per_block,
+    init_state,
+    pack_queries,
+    unpack_outputs,
+)
 
 
 def _paged_decode_kernel(
     table_ref,  # SMEM [B, n_logical] i32 (scalar prefetch)
     len_ref,  # SMEM [B] i32 (scalar prefetch)
-    q_ref,  # [1, G, hd]
-    k_ref,  # [1, block_size, 1, hd] — physical block table_ref[b, j]
-    v_ref,  # [1, block_size, 1, hd]
-    o_ref,  # [1, G, hd]
-    m_scr,  # [G, 128] f32
-    l_scr,  # [G, 128] f32
-    acc_scr,  # [G, hd] f32
+    q_ref,  # [R, W] packed queries of this lane block
+    k_ref,  # [block_size, W] — physical block table_ref[b, j]
+    v_ref,  # [block_size, W]
+    o_ref,  # [R, W]
+    m_scr,  # [R, 128] f32
+    l_scr,  # [R, 128] f32
+    acc_scr,  # [R, W] f32
     *,
     sm_scale: float,
     block_size: int,
@@ -53,44 +62,20 @@ def _paged_decode_kernel(
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_state(m_scr, l_scr, acc_scr)
 
     k_start = j * block_size
 
     @pl.when(k_start < length)
     def _compute():
-        q = q_ref[0]  # [G, hd]
-        k = k_ref[0, :, 0, :]  # [block_size, hd]
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, block_size]
-        s = s * sm_scale
-        G = s.shape[0]
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (G, block_size), 1)
-        mask = k_pos < length
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True), l_scr.shape
+        attend_tile(
+            q_ref[...], k_ref[...], v_ref[...], k_start, length,
+            m_scr, l_scr, acc_scr, sm_scale,
         )
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
     @pl.when(j == num_logical - 1)
     def _emit():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
+        emit(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -105,16 +90,15 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, Hq, hd = q.shape
-    bs, KVH = k_pool.shape[1], k_pool.shape[2]
+    NB, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     if Hq % KVH != 0:
         raise ValueError(f"q heads {Hq} not a multiple of kv heads {KVH}")
-    G = Hq // KVH
     n_logical = table.shape[1]
     if sm_scale is None:
         sm_scale = float(1.0 / np.sqrt(hd))
-
-    # q regrouped so each kv head's G query heads are contiguous
-    q3 = q.reshape(B, KVH, G, hd).reshape(B, KVH * G, hd)
+    hp = heads_per_block(KVH, hd)
+    W = hp * hd
+    R = hp * (Hq // KVH)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -124,35 +108,41 @@ def paged_decode_attention(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block table + lengths land in SMEM up front
-        grid=(B, KVH, n_logical),
+        grid=(B, KVH // hp, n_logical),
         in_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, h, j, table_ref, len_ref: (b, h, 0)),
             pl.BlockSpec(
-                (1, bs, 1, hd),
-                lambda b, h, j, table_ref, len_ref: (table_ref[b, j], 0, h, 0),
+                (None, None, R, W), lambda b, h, j, tab, lens: (b, h, 0, 0)
             ),
             pl.BlockSpec(
-                (1, bs, 1, hd),
-                lambda b, h, j, table_ref, len_ref: (table_ref[b, j], 0, h, 0),
+                (None, bs, W), lambda b, h, j, tab, lens: (tab[b, j], 0, h)
+            ),
+            pl.BlockSpec(
+                (None, bs, W), lambda b, h, j, tab, lens: (tab[b, j], 0, h)
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, G, hd), lambda b, h, j, table_ref, len_ref: (b, h, 0)
+            (None, None, R, W), lambda b, h, j, tab, lens: (b, h, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((R, 128), jnp.float32),
+            pltpu.VMEM((R, 128), jnp.float32),
+            pltpu.VMEM((R, W), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KVH // hp, R, W), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32), q3, k_pool, v_pool)
-    return out.reshape(B, KVH, G, hd).reshape(B, Hq, hd)
+    )(
+        table.astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        pack_queries(q, KVH, hp),
+        k_pool.reshape(NB, bs, KVH * hd),
+        v_pool.reshape(NB, bs, KVH * hd),
+    )
+    return unpack_outputs(out, hp, hd)

@@ -248,6 +248,7 @@ def measure_cell(arch: str, shape_name: str, multi_pod: bool, policy: str = "dp_
         by_op=by_op,
         counts=counts,
     )
+    pk = constants.peaks(constants.DRYRUN_TARGET)
     flops_global = flops_dev * num_devices + extra_flops
     bytes_global = bytes_dev * num_devices + extra_bytes
     report = analysis.RooflineReport(
@@ -259,9 +260,9 @@ def measure_cell(arch: str, shape_name: str, multi_pod: bool, policy: str = "dp_
         hlo_bytes=bytes_global,
         collective=coll,
         model_flops=analysis.model_flops_for(cfg, shape),
-        compute_s=flops_global / (num_devices * constants.PEAK_FLOPS_BF16),
-        memory_s=bytes_global / (num_devices * constants.HBM_BW),
-        collective_s=coll.global_bytes / (num_devices * constants.ICI_BW),
+        compute_s=flops_global / (num_devices * pk.flops_bf16),
+        memory_s=bytes_global / (num_devices * pk.hbm_bw),
+        collective_s=coll.global_bytes / (num_devices * pk.ici_bw),
     )
     row = report.row()
     row["collective_by_op_gb"] = {k: v * num_devices / 1e9 for k, v in by_op.items()}

@@ -1,8 +1,10 @@
 """Collaborative serving driver: ``python -m repro.launch.serve --arch <id>``.
 
-Boots a reduced model, partitions it into stages over a small edge topology,
-runs DTO-EE configuration phases between time slots, and serves Poisson
-request streams through the REAL model with live early-exit confidences.
+Boots the model at its published widths (``--reduced`` selects the tiny
+``ArchConfig.reduced()`` sibling for CPU runs), partitions it into stages
+over a small edge topology, runs DTO-EE configuration phases between time
+slots, and serves Poisson request streams through the REAL model with live
+early-exit confidences.  Weights are random, drawn from ``--seed``.
 
 Two control-plane modes:
 
@@ -45,6 +47,7 @@ from repro.core.thresholds import synthetic_validation
 from repro.core.topology import build_edge_network, NetworkSpec, with_resampled_capacities
 from repro.core.types import DtoHyperParams
 from repro.data import RequestConfig, poisson_requests
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving import CollaborativeEngine
 
@@ -70,9 +73,33 @@ def _write_obs(args, stats) -> None:
         print(f"stats report: {args.stats_report}", flush=True)
 
 
+def build_engine(cfg, seed: int, num_eds: int) -> CollaborativeEngine:
+    """Random weights from ``seed`` on a random edge topology of ``num_eds``
+    end devices and 3-4 replicas per stage, with a synthetic exit profile."""
+    params = jax.jit(model_lib.init_params, static_argnums=1)(
+        jax.random.key(seed), cfg
+    )
+    profile = profile_from_arch(cfg)
+    topo = build_edge_network(
+        seed=seed,
+        profile=profile,
+        spec=NetworkSpec(num_eds=num_eds, es_per_stage=(3, 4)),
+    )
+    exit_profile = synthetic_validation(seed=seed + 1, profile=profile)
+    return CollaborativeEngine(
+        params, cfg, topo, profile, exit_profile, DtoHyperParams(), seed=seed
+    )
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument(
+        "--reduced",
+        action="store_true",
+        help="serve the arch's tiny smoke-test sibling (d_model 128, vocab "
+        "512) instead of its published widths — for CPU runs",
+    )
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--slot-seconds", type=float, default=5.0)
     ap.add_argument("--requests-per-slot", type=int, default=24)
@@ -179,19 +206,12 @@ def main() -> None:
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get_config(args.arch).reduced()
-    params = model_lib.init_params(jax.random.key(args.seed), cfg)
-    profile = profile_from_arch(cfg)
-    topo = build_edge_network(
-        seed=args.seed,
-        profile=profile,
-        spec=NetworkSpec(num_eds=args.num_eds, es_per_stage=(3, 4)),
-    )
-    exit_profile = synthetic_validation(seed=args.seed + 1, profile=profile)
-    engine = CollaborativeEngine(
-        params, cfg, topo, profile, exit_profile, DtoHyperParams(), seed=args.seed
-    )
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    engine = build_engine(cfg, args.seed, args.num_eds)
 
     rng = np.random.default_rng(args.seed)
     rcfg = RequestConfig(

@@ -8,11 +8,9 @@ per-stage FLOP/byte counts in :mod:`repro.roofline.analysis` to report, per
 stage and phase, how far measured compute sits from the roofline bound —
 turning the ROADMAP's "as fast as the hardware allows" into a measured gap.
 
-Utilization > 1 is possible and meaningful on this host: the bound assumes
-the TPU-class constants in ``roofline/constants.py`` while tests run on CPU,
-and tiny stage programs are launch-latency-bound — the *relative* trend
-across stages/phases is the signal, and the numbers become absolute on the
-target part.
+The bound uses the published peaks of ``device_kind``
+(``roofline/constants.py``); the caller names the device the wall times
+were taken on, and a kind without published peaks is an error.
 """
 from __future__ import annotations
 
@@ -25,8 +23,9 @@ from repro.roofline.analysis import (
 __all__ = ["roofline_utilization"]
 
 
-def roofline_utilization(tracer, cfg) -> dict:
-    """Measured-vs-roofline utilization per (stage, phase) of one serve.
+def roofline_utilization(tracer, cfg, device_kind: str) -> dict:
+    """Measured-vs-roofline utilization per (stage, phase) of one serve on
+    ``device_kind``.
 
     Returns ``{"stage{h}.{phase}": {...}}`` rows with the measured wall
     time, the analytic FLOP/byte totals for the device work shipped, the
@@ -36,7 +35,7 @@ def roofline_utilization(tracer, cfg) -> dict:
     for (stage, phase), cw in sorted(tracer.compute_wall.items()):
         flops = stage_step_flops(cfg, stage, cw.tokens)
         nbytes = stage_step_bytes(cfg, stage, cw.calls, cw.tokens)
-        bound_s = stage_roofline_bound_s(flops, nbytes)
+        bound_s = stage_roofline_bound_s(flops, nbytes, device_kind)
         row = {
             "stage": stage,
             "phase": phase,

@@ -53,7 +53,8 @@ class RooflineReport:
     def roofline_fraction(self) -> float:
         """What fraction of the bound-term time is useful model compute —
         the headline score: model_flops_time / achievable_step_time."""
-        ideal = self.model_flops / (self.num_devices * constants.PEAK_FLOPS_BF16)
+        peak = constants.peaks(constants.DRYRUN_TARGET).flops_bf16
+        ideal = self.model_flops / (self.num_devices * peak)
         return ideal / self.bound_s if self.bound_s > 0 else 0.0
 
     def row(self) -> dict:
@@ -100,11 +101,11 @@ def stage_step_bytes(
     return weights + activations
 
 
-def stage_roofline_bound_s(flops: float, nbytes: float) -> float:
-    """Single-chip roofline time bound: max of the compute and memory terms."""
-    return max(
-        flops / constants.PEAK_FLOPS_BF16, nbytes / constants.HBM_BW
-    )
+def stage_roofline_bound_s(flops: float, nbytes: float, device_kind: str) -> float:
+    """Single-chip roofline time bound on ``device_kind``: max of the
+    compute and memory terms."""
+    pk = constants.peaks(device_kind)
+    return max(flops / pk.flops_bf16, nbytes / pk.hbm_bw)
 
 
 def model_flops_for(cfg, shape) -> float:
@@ -135,6 +136,7 @@ def build_report(
     flops = float(cost_analysis.get("flops", 0.0)) * num_devices
     nbytes = float(cost_analysis.get("bytes accessed", 0.0)) * num_devices
     coll = collective_stats(hlo_text, num_devices)
+    pk = constants.peaks(constants.DRYRUN_TARGET)
     return RooflineReport(
         arch=arch,
         shape=shape_name,
@@ -144,7 +146,7 @@ def build_report(
         hlo_bytes=nbytes,
         collective=coll,
         model_flops=model_flops,
-        compute_s=flops / (num_devices * constants.PEAK_FLOPS_BF16),
-        memory_s=nbytes / (num_devices * constants.HBM_BW),
-        collective_s=coll.global_bytes / (num_devices * constants.ICI_BW),
+        compute_s=flops / (num_devices * pk.flops_bf16),
+        memory_s=nbytes / (num_devices * pk.hbm_bw),
+        collective_s=coll.global_bytes / (num_devices * pk.ici_bw),
     )

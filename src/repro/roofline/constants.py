@@ -1,8 +1,39 @@
-"""Target-hardware constants (TPU v5e-class chip)."""
+"""Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link (per-chip effective collective bandwidth)
+Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s in bf16, 819 GB/s
+of HBM bandwidth, 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+(50 GB/s per link).  A device kind missing from the table is an error:
+there is no default peak.
+"""
+from __future__ import annotations
+
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops_bf16: float  # FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_bw: float  # bytes/s per interconnect link
+
+
+PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+# the part the multi-pod dry-run compiles for
+DRYRUN_TARGET = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
+
 
 BYTES = {
     "f32": 4,

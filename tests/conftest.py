@@ -8,6 +8,11 @@ import jax
 jax.config.update("jax_platform_name", "cpu")
 
 
+def pytest_configure(config):
+    # registered so the mark is known; nothing deselects on it
+    config.addinivalue_line("markers", "slow: a test that takes minutes")
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

@@ -79,6 +79,7 @@ def test_flash_attention_non_causal(rng):
     "B,S,Hq,KVH,hd,block",
     [
         (2, 300, 8, 2, 64, 64),
+        (2, 200, 4, 4, 64, 64),  # MHA at hd 64 (stablelm): 2 heads per lane block
         (1, 512, 4, 4, 128, 128),
         (3, 1000, 16, 4, 64, 256),  # ragged lengths below
     ],
@@ -100,6 +101,7 @@ def test_decode_attention_matches_ref(rng, dtype, B, S, Hq, KVH, hd, block):
     "B,Hq,KVH,hd,NB,bs,nlog",
     [
         (3, 4, 2, 32, 9, 16, 4),  # GQA 2:1
+        (2, 4, 4, 64, 9, 16, 4),  # MHA at hd 64 (stablelm), block size 16
         (2, 2, 2, 16, 5, 1, 7),  # degenerate one-token blocks
         (1, 8, 4, 64, 12, 8, 3),  # single row
     ],

@@ -391,7 +391,9 @@ def test_serve_trace_exports_and_validates(traced):
 
 def test_serve_roofline_rows(traced, engine):
     _, tracer, _ = traced
-    rows = roofline_utilization(tracer, engine.cfg)
+    # the join's arithmetic against v5e peaks; a CPU wall time over a TPU
+    # bound is no device metric, so only the plumbing is checked
+    rows = roofline_utilization(tracer, engine.cfg, "TPU v5 lite")
     assert rows
     phases = {r["phase"] for r in rows.values()}
     assert phases == {"prefill", "decode"}

@@ -114,8 +114,8 @@ def test_roofline_report_terms():
         hlo_bytes=1e15,
         collective=collective_stats(FAKE_HLO, 256),
         model_flops=5e17,
-        compute_s=1e18 / (256 * constants.PEAK_FLOPS_BF16),
-        memory_s=1e15 / (256 * constants.HBM_BW),
+        compute_s=1e18 / (256 * constants.peaks("TPU v5 lite").flops_bf16),
+        memory_s=1e15 / (256 * constants.peaks("TPU v5 lite").hbm_bw),
         collective_s=1.0,
     )
     assert rep.dominant == "compute"  # 19.8s compute > 1s collective

@@ -123,3 +123,55 @@ def test_data_pipeline_deterministic_resume():
     np.testing.assert_array_equal(
         np.asarray(batches[3]["tokens"]), np.asarray(resumed["tokens"])
     )
+
+
+REPO = os.path.join(os.path.dirname(__file__), "..")
+
+
+def test_compile_cache_goes_where_the_env_var_says(tmp_path):
+    code = (
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "import jax, jax.numpy as jnp\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n"
+    )
+    env = dict(
+        os.environ,
+        PYTHONPATH=SRC,
+        JAX_PLATFORMS="cpu",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
+    assert any(tmp_path.iterdir())  # the compiled program was written there
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == os.path.join(os.path.realpath(REPO), ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_smoke_refuses_a_host_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert "no TPU" in out.stderr
