@@ -1,0 +1,9 @@
+"""device: the share of the traced window in which no operation ran on
+the chip (1 - the union of device op intervals over the window)."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
