@@ -6,8 +6,6 @@ both decode modes on one fixed workload (same prompts, same arrival process,
 same thresholds), asserts token-identical sequences and exit decisions
 between the modes AND against the monolithic ``model.prefill`` +
 ``model.decode_step`` reference, and measures wall-clock decode tokens/s.
-A traced run then joins measured per-stage wall time with the analytic
-roofline FLOP/byte counts into per-(stage, phase) utilization rows.
 Results land in ``BENCH_decode.json``.
 
 ``--cache-layout paged`` instead A/Bs the PAGED slot store against the dense
@@ -39,7 +37,6 @@ from repro.core.topology import NetworkSpec, build_edge_network
 from repro.core.types import DtoHyperParams
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
-from repro.roofline.constants import PEAKS
 from repro.serving import CollaborativeEngine, monolithic_generate
 
 
@@ -164,69 +161,6 @@ def bench_decode(
             "threshold": float(eng.thresholds[0]),
         },
         "by_gen_len": by_gen,
-    }
-
-
-def bench_roofline(
-    eng: CollaborativeEngine,
-    gen_len: int,
-    n_requests: int,
-    prompt_len: int,
-    batch_size: int,
-    arrival_rate: float,
-    device_kind: str,
-    serve_seed: int = 123,
-    num_slots: int | None = None,
-) -> dict:
-    """Measured-vs-roofline utilization of one traced cached-decode serve.
-
-    The tracer accumulates real wall seconds around every jitted stage
-    program (prefill and decode separately) plus the device work shipped;
-    joining with the analytic per-stage FLOP/byte counts turns that into a
-    per-(stage, phase) utilization against ``device_kind``'s bound."""
-    from repro.obs import SpanTracer, roofline_utilization
-
-    rng = np.random.default_rng(0)
-    prompts = [
-        rng.integers(0, eng.cfg.vocab_size, size=prompt_len).astype(np.int32)
-        for _ in range(n_requests)
-    ]
-    tracer = SpanTracer()
-    eng.rng = np.random.default_rng(serve_seed)
-    eng.serve(  # warmup/compile so wall times are steady-state
-        prompts,
-        arrival_rate=arrival_rate,
-        batch_size=batch_size,
-        gen_len=gen_len,
-        decode_mode="cached",
-        num_slots=num_slots,
-    )
-    eng.rng = np.random.default_rng(serve_seed)
-    eng.serve(
-        prompts,
-        arrival_rate=arrival_rate,
-        batch_size=batch_size,
-        gen_len=gen_len,
-        decode_mode="cached",
-        num_slots=num_slots,
-        tracer=tracer,
-    )
-    rows = roofline_utilization(tracer, eng.cfg, device_kind)
-    for key, r in rows.items():
-        print(
-            f"roofline {key:18s}: wall {r['measured_wall_s']*1e3:8.2f}ms  "
-            f"bound {r['bound_s']*1e6:8.2f}us  util {r['utilization']:.2e}  "
-            f"calls {r['calls']:4d}  padded {r['padded_row_frac']*100:4.1f}%"
-        )
-    return {
-        "workload": {
-            "n_requests": n_requests,
-            "prompt_len": prompt_len,
-            "gen_len": gen_len,
-            "batch_size": batch_size,
-        },
-        "device_kind": device_kind,
-        "by_stage_phase": rows,
     }
 
 
@@ -408,20 +342,6 @@ def validate_schema(payload: dict) -> None:
             m = entry["by_mode"][mode]
             for field in ("wall_s", "tokens_per_s", "generated_tokens", "num_batches"):
                 assert np.isfinite(m[field]), f"{mode}.{field} not finite"
-    if "not_measured" in payload["roofline"]:
-        return
-    roof = payload["roofline"]["by_stage_phase"]
-    assert roof, "roofline join produced no (stage, phase) rows"
-    phases = {r["phase"] for r in roof.values()}
-    assert "prefill" in phases and "decode" in phases, (
-        f"roofline missing a phase: saw {sorted(phases)}"
-    )
-    for key, r in roof.items():
-        assert r["measured_wall_s"] > 0, f"{key}: no measured wall time"
-        assert r["bound_s"] > 0 and np.isfinite(r["utilization"]), (
-            f"{key}: degenerate roofline bound"
-        )
-        assert r["calls"] > 0 and r["device_tokens"] > 0
 
 
 def main() -> None:
@@ -504,24 +424,7 @@ def main() -> None:
         repeats=args.repeats,
         num_slots=args.num_slots,
     )
-    device_kind = jax.devices()[0].device_kind
-    if device_kind in PEAKS:
-        roofline_res = bench_roofline(
-            eng,
-            gen_len=max(args.gen_lens),
-            n_requests=args.n_requests,
-            prompt_len=args.prompt_len,
-            batch_size=args.batch_size,
-            arrival_rate=args.arrival_rate,
-            device_kind=device_kind,
-            num_slots=args.num_slots,
-        )
-    else:
-        roofline_res = {
-            "not_measured": f"no published peaks for device kind {device_kind!r}"
-        }
-        print(f"roofline: not measured ({roofline_res['not_measured']})")
-    payload = {"decode": res, "roofline": roofline_res, "meta": meta}
+    payload = {"decode": res, "meta": meta}
     validate_schema(payload)
     with open(args.out, "w") as f:
         json.dump(payload, f, indent=2)

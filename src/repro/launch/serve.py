@@ -21,8 +21,10 @@ Observability flags (see src/repro/serving/README.md, "Observability"):
     Chrome-trace/Perfetto JSON (open at https://ui.perfetto.dev).  Slotted
     mode traces the LAST slot (one trace file, one serve).
   * ``--stats-report report.json`` — write the machine-readable
-    ``ServeStats.report()`` (summary + per-request delay decomposition +
-    metrics registry snapshot) of the traced serve.
+    ``ServeStats.report()`` (summary + the host-span record: per-span
+    count, total and self seconds, stage batches, compiles by span +
+    per-request delay decomposition + metrics registry snapshot) of the
+    traced serve.
 """
 from __future__ import annotations
 
@@ -202,7 +204,8 @@ def main() -> None:
         default=None,
         metavar="PATH",
         help="write the machine-readable ServeStats.report() JSON (summary "
-        "+ delay decomposition + metrics) of the traced serve to PATH",
+        "+ host-span record + delay decomposition + metrics) of the traced "
+        "serve to PATH",
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()

@@ -12,7 +12,6 @@ from repro.obs.metrics import (
     MetricsCollector,
     MetricsRegistry,
 )
-from repro.obs.roofline_hook import roofline_utilization
 from repro.obs.stream import HOOKS, InstrumentationStream, build_stream
 from repro.obs.trace import SPAN_KINDS, NullTracer, SimClock, Span, SpanTracer
 
@@ -27,7 +26,6 @@ __all__ = [
     "Histogram",
     "MetricsCollector",
     "MetricsRegistry",
-    "roofline_utilization",
     "HOOKS",
     "InstrumentationStream",
     "build_stream",

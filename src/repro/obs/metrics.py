@@ -187,8 +187,6 @@ class MetricsCollector:
     cheap enough to leave on for every serve.
     """
 
-    wants_wall_clock = False
-
     def __init__(self, registry: MetricsRegistry | None = None):
         self.registry = registry or MetricsRegistry()
         #: realized (confidence, exit_stage) pairs — the control plane's

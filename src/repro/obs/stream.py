@@ -29,7 +29,7 @@ Hook vocabulary (all timestamps are simulated seconds):
   on_batch(done, node, gflops, wall, queue_depth, **detail)
                                       one stage batch; detail carries stage,
                                       rids, t_dispatch, t_start, n_rows,
-                                      n_tokens, is_decode, wall_clock_s
+                                      is_decode
   on_pool(t, node, used_fraction, hit_blocks, total_blocks)  paged pool sample
   on_exit(t, rid, stage, conf)        retirement
   on_resubmit(t, rid)                 fail-stop re-execution restart
@@ -76,11 +76,6 @@ class InstrumentationStream:
 
     def __init__(self, subscribers):
         self.subscribers = tuple(s for s in subscribers if s is not None)
-        #: any subscriber wants REAL wall-clock timings of stage programs
-        #: (the engine only pays the perf_counter reads when this is set)
-        self.wants_wall = any(
-            getattr(s, "wants_wall_clock", False) for s in self.subscribers
-        )
         for name in HOOKS:
             fns = tuple(
                 getattr(s, name)

@@ -24,16 +24,15 @@ reconcile with the reported delay (asserted in tests and by
 :func:`repro.obs.attribution.decompose`).
 
 Hot-path cost: each hook appends ONE compact event tuple; span trees,
-instants, counters, and the roofline accumulators are materialized lazily by
+instants and counters are materialized lazily by
 replaying the event log on first view access (views are read after the
 serve, so the serve itself pays only the appends — the <3% overhead budget
 the serving benchmark's tracing A/B enforces).
 
 Timestamps are **simulated** seconds; the tracer has no clock of its own —
 callers inject event times explicitly (:class:`SimClock` tracks the latest
-one for exporters).  Wall-clock durations of the real jitted stage programs
-ride along separately (``wants_wall_clock``) and feed the roofline join in
-:mod:`repro.obs.roofline_hook`.
+one for exporters).  The engine's host work on the real clock is kept apart,
+in :mod:`repro.obs.host`.
 
 When tracing is off the engine skips every emission (``stream is None``), so
 the disabled path is bitwise identical to an untraced build; :class:`NullTracer`
@@ -77,24 +76,11 @@ class SimClock:
             self.now = t
 
 
-@dataclasses.dataclass
-class _ComputeWall:
-    """Accumulated REAL wall-clock of one (stage, phase) program across a
-    serve — the measured half of the roofline join."""
-
-    wall_s: float = 0.0
-    calls: int = 0
-    rows: int = 0  # padded device rows (machine work)
-    live_rows: int = 0
-    tokens: int = 0  # padded rows x pass seq length (device tokens)
-    gflops: float = 0.0  # modeled GFLOPs charged by the sim clock
-
-
 class _Materialized:
     """Span trees etc. rebuilt from the event log by :meth:`SpanTracer._replay`."""
 
     __slots__ = (
-        "spans", "instants", "counters", "compute_wall", "arrival", "done",
+        "spans", "instants", "counters", "arrival", "done",
         "attempts", "batches", "cursor", "queue_start",
     )
 
@@ -102,7 +88,6 @@ class _Materialized:
         self.spans: dict[int, list[Span]] = {}
         self.instants: list[dict] = []
         self.counters: list[tuple[float, str, int, float]] = []
-        self.compute_wall: dict[tuple[int, str], _ComputeWall] = {}
         self.arrival: dict[int, float] = {}
         self.done: dict[int, float] = {}
         self.attempts: dict[int, int] = {}
@@ -129,8 +114,6 @@ class SpanTracer:
     simulator.  Every hook is one tuple append; the views below replay the
     log on demand.
     """
-
-    wants_wall_clock = True  # ask the engine to time its stage programs
 
     def __init__(self):
         self.clock = SimClock()
@@ -191,14 +174,12 @@ class SpanTracer:
         t_dispatch: float = 0.0,
         t_start: float = 0.0,
         n_rows: int = 0,
-        n_tokens: int = 0,
         is_decode: bool = False,
-        wall_clock_s: float = 0.0,
         **_: Any,
     ) -> None:
         self._events.append((
-            "batch", t, node, gflops, queue_depth, stage, rids, t_dispatch,
-            t_start, n_rows, n_tokens, is_decode, wall_clock_s,
+            "batch", t, node, queue_depth, stage, rids, t_dispatch, t_start,
+            n_rows, is_decode,
         ))
 
     def on_pool(
@@ -234,8 +215,8 @@ class SpanTracer:
                 _, t, rid, node = ev
                 m.queue_start[rid] = (t, node)
             elif op == "batch":
-                (_, t, node, gflops, queue_depth, stage, rids, t_dispatch,
-                 t_start, n_rows, n_tokens, is_decode, wall_clock_s) = ev
+                (_, t, node, queue_depth, stage, rids, t_dispatch, t_start,
+                 n_rows, is_decode) = ev
                 for rid in rids:
                     qs = m.queue_start.pop(rid, (t_dispatch, node))
                     m.add_span(rid, "queue", qs[0], t_dispatch, node, stage)
@@ -245,16 +226,6 @@ class SpanTracer:
                                {"decode": is_decode})
                     m.cursor[rid] = t
                 m.counters.append((t, "queue_depth", node, float(queue_depth)))
-                key = (stage, "decode" if is_decode else "prefill")
-                cw = m.compute_wall.get(key)
-                if cw is None:
-                    cw = m.compute_wall[key] = _ComputeWall()
-                cw.wall_s += wall_clock_s
-                cw.calls += 1
-                cw.rows += n_rows
-                cw.live_rows += len(rids)
-                cw.tokens += n_tokens
-                cw.gflops += gflops
                 m.batches.append(
                     (t_start, t, node, stage, len(rids), n_rows, is_decode)
                 )
@@ -341,10 +312,6 @@ class SpanTracer:
         return self._replay().counters
 
     @property
-    def compute_wall(self) -> dict[tuple[int, str], _ComputeWall]:
-        return self._replay().compute_wall
-
-    @property
     def arrival(self) -> dict[int, float]:
         return self._replay().arrival
 
@@ -405,8 +372,6 @@ class NullTracer:
     """Zero-cost stub: every hook is a no-op.  The engine never calls into a
     tracer unless one is attached, so this exists for call sites that want
     an unconditional object (e.g. library code taking ``tracer=NullTracer()``)."""
-
-    wants_wall_clock = False
 
     def __getattr__(self, name: str):
         if name.startswith("on_") or name.startswith("add_"):

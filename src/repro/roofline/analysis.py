@@ -76,38 +76,6 @@ class RooflineReport:
         }
 
 
-def stage_step_flops(cfg, stage: int, n_tokens: int) -> float:
-    """Forward FLOPs of serving-stage ``stage`` (1-based) over ``n_tokens``
-    device tokens: 2 * active_params_h per token — the same accounting as
-    ``model_flops_for`` (param-FLOPs dominate; attention-vs-cache reads are
-    charged to the byte side)."""
-    from repro.core.profiles import stage_param_counts
-
-    params = stage_param_counts(cfg)[stage - 1]
-    return 2.0 * params * n_tokens
-
-
-def stage_step_bytes(
-    cfg, stage: int, n_calls: int, n_tokens: int, dtype_bytes: int = 2
-) -> float:
-    """HBM traffic of ``n_calls`` invocations of stage ``stage``: the weight
-    stream (params * dtype_bytes, re-read every call — the decode-side
-    floor) plus the activation stream (tokens * d_model in and out)."""
-    from repro.core.profiles import stage_param_counts
-
-    params = stage_param_counts(cfg)[stage - 1]
-    weights = float(n_calls) * params * dtype_bytes
-    activations = 2.0 * n_tokens * cfg.d_model * dtype_bytes
-    return weights + activations
-
-
-def stage_roofline_bound_s(flops: float, nbytes: float, device_kind: str) -> float:
-    """Single-chip roofline time bound on ``device_kind``: max of the
-    compute and memory terms."""
-    pk = constants.peaks(device_kind)
-    return max(flops / pk.flops_bf16, nbytes / pk.hbm_bw)
-
-
 def model_flops_for(cfg, shape) -> float:
     """6*N*D (dense) / 6*N_active*D (MoE); decode counts one new token."""
     n = cfg.param_count(active_only=cfg.moe is not None)

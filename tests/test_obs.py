@@ -3,8 +3,8 @@
 Unit layer: the tracer/metrics/export/stream primitives driven by hand with
 synthetic event sequences (exact expected spans).  Integration layer: one
 traced cached-decode serve on a tiny real engine, shared across tests —
-span-tree completeness under mid-decode admission, metrics totals, roofline
-rows, export validation, and the disabled-path bitwise-identity guarantee.
+span-tree completeness under mid-decode admission, metrics totals, export
+validation, and the disabled-path bitwise-identity guarantee.
 """
 import json
 
@@ -31,7 +31,6 @@ from repro.obs import (
     build_stream,
     chrome_trace,
     decompose,
-    roofline_utilization,
     validate_chrome_trace,
 )
 from repro.serving import CollaborativeEngine
@@ -117,7 +116,7 @@ def _emit_one_request(tr, rid=0, base=0.0):
     tr.on_batch(
         base + 0.03, 2, 1.0, 0.02, 0,
         stage=1, rids=(rid,), t_dispatch=base + 0.015, t_start=base + 0.02,
-        n_rows=4, n_tokens=48, is_decode=False, wall_clock_s=1e-4,
+        n_rows=4, is_decode=False,
     )
     tr.on_exit(base + 0.03, rid, stage=1, conf=0.9)
 
@@ -152,7 +151,7 @@ def test_tracer_resubmit_accounts_lost_time():
     tr.on_batch(
         0.05, 3, 1.0, 0.015, 0,
         stage=1, rids=(7,), t_dispatch=0.035, t_start=0.04,
-        n_rows=1, n_tokens=12, is_decode=False, wall_clock_s=1e-4,
+        n_rows=1, is_decode=False,
     )
     tr.on_exit(0.05, 7, stage=1, conf=0.8)
     assert tr.check_tree(7) == []
@@ -209,7 +208,6 @@ def test_null_tracer_is_inert():
     nt.on_batch(0.0, 1, 1.0, 0.1, 0)  # arbitrary hooks absorb anything
     nt.on_exit(0.0, 1, 2, 0.5)
     nt.add_span(0, "queue", 0.0, 1.0)
-    assert nt.wants_wall_clock is False
     with pytest.raises(AttributeError):
         nt.spans
 
@@ -242,13 +240,11 @@ def test_stream_single_subscriber_binds_directly():
     st.on_pool(0.0, 1, 0.5)
 
 
-def test_stream_fans_out_and_aggregates_wants_wall():
+def test_stream_fans_out():
     a, b = _ExitCounter(), _ExitCounter()
     st = build_stream(a, b)
     st.on_exit(1.0, 3, 2, 0.7)
     assert a.calls == b.calls == [(1.0, 3, 2, 0.7)]
-    assert st.wants_wall is False
-    assert build_stream(a, SpanTracer()).wants_wall is True  # tracer wants it
 
 
 # ---------------------------------------------------------------------------
@@ -387,21 +383,6 @@ def test_serve_trace_exports_and_validates(traced):
     # both request tracks and node busy tracks are present
     pids = {e["pid"] for e in payload["traceEvents"]}
     assert 1 in pids and any(p >= 1000 for p in pids)
-
-
-def test_serve_roofline_rows(traced, engine):
-    _, tracer, _ = traced
-    # the join's arithmetic against v5e peaks; a CPU wall time over a TPU
-    # bound is no device metric, so only the plumbing is checked
-    rows = roofline_utilization(tracer, engine.cfg, "TPU v5 lite")
-    assert rows
-    phases = {r["phase"] for r in rows.values()}
-    assert phases == {"prefill", "decode"}
-    for row in rows.values():
-        assert row["calls"] > 0 and row["device_tokens"] > 0
-        assert row["measured_wall_s"] > 0  # wants_wall_clock was honored
-        assert row["bound_s"] > 0
-        assert np.isfinite(row["utilization"]) and row["utilization"] > 0
 
 
 def test_disabled_path_is_bitwise_identical(engine, traced):
