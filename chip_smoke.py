@@ -122,8 +122,8 @@ def check_exit_confidence(cfg, key, B: int) -> str:
     d, V = cfg.d_model, cfg.vocab_size
     kh, kw = jax.random.split(key)
     h = jax.random.normal(kh, (B, d), cfg.dtype)
-    # the serving path hands the head over as the f32 master weight
-    w = jax.random.normal(kw, (d, V), jnp.float32) / d**0.5
+    # the serving path hands the head over in the compute dtype
+    w = (jax.random.normal(kw, (d, V), jnp.float32) / d**0.5).astype(cfg.dtype)
     conf, tok = ops.exit_confidence(h, w)
     want_conf, _ = ref.exit_confidence_ref(h, w)
     wb = w.astype(h.dtype)
@@ -328,9 +328,10 @@ def serve_phase(cfg, seed: int, clock: CompileClock) -> dict:
     n_params = sum(int(a.size) for a in jax.tree.leaves(engine.programs.params))
     log(f"(c) engine: {n_params / 1e9:.4f} B params (f32), built in "
         f"{perf_counter() - t0:.1f} s")
+    log(f"(c) serving weights: {engine.programs.weight_bytes}")
     engine.configuration_phase()
     log(f"(c) configuration phase: thresholds {engine.thresholds}")
-    check_compiled_programs(cfg, engine.programs.params)
+    check_compiled_programs(cfg, engine.programs.weights)
 
     rng = np.random.default_rng(seed)
     prompts = [
@@ -367,7 +368,7 @@ def serve_phase(cfg, seed: int, clock: CompileClock) -> dict:
             f"({row['programs_compiled']} programs)")
         return stats, row
 
-    report = {}
+    report = {"weight_bytes": dict(engine.programs.weight_bytes)}
     runs = {}
     for layout in ("dense", "paged"):
         cold, report[f"{layout} cold"] = serve(layout, f"serve {layout} (cold)")

@@ -121,8 +121,10 @@ def exit_confidence(
     block_v: int = 1024,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(top-1 softmax prob, argmax) of ``h @ w`` with the head ``w`` cast to
-    the activation dtype, as the oracle casts it: the serving path holds f32
-    master weights, and a bf16 head tile is what fits the kernel's VMEM."""
+    the activation dtype, as the oracle casts it: a bf16 head tile is what
+    fits the kernel's VMEM.  The serving programs are handed the head in that
+    dtype already (``model.serving_params``), so the cast is a no-op there;
+    training and the monolithic steps hand it f32 master weights."""
     be = get_backend()
     if be == "xla":
         return ref.exit_confidence_ref(h, w)

@@ -4,8 +4,10 @@ Conventions:
   * params are nested dicts of jnp arrays; leaf names drive sharding rules
     (see repro.sharding.specs.param_spec).
   * every ``apply``-style function takes activations in compute dtype
-    (bf16 by default) while params stay in param dtype (f32 master copies);
-    casting happens at the matmul boundary.
+    (bf16 by default) and params in whatever dtype they are held in:
+    training holds f32 master copies, serving holds the matrices in the
+    compute dtype (``model.serving_params``); casting happens at the matmul
+    boundary either way.
 """
 from __future__ import annotations
 
@@ -29,7 +31,11 @@ def dense_init(key, d_in: int, d_out: int, *, scale: float = 1.0, dtype=jnp.floa
     return truncated_normal_init(key, (d_in, d_out), scale, dtype)
 
 
-def matmul(x: jnp.ndarray, w: jnp.ndarray, *, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
+# the dtype ``matmul`` feeds the MXU
+COMPUTE_DTYPE = jnp.bfloat16
+
+
+def matmul(x: jnp.ndarray, w: jnp.ndarray, *, compute_dtype=COMPUTE_DTYPE) -> jnp.ndarray:
     """x @ w with both operands cast to the compute dtype (MXU-friendly)."""
     return jnp.matmul(x.astype(compute_dtype), w.astype(compute_dtype))
 
@@ -129,7 +135,9 @@ def embedding_init(key, vocab: int, d: int) -> Params:
 
 
 def embed(params: Params, tokens: jnp.ndarray, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
-    return params["embed"].astype(compute_dtype)[tokens]
+    """The tokens' rows, gathered in the table's dtype, then cast: only the
+    gathered rows are converted, so the table is served as it is held."""
+    return params["embed"][tokens].astype(compute_dtype)
 
 
 # ---------------------------------------------------------------------------

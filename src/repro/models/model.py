@@ -90,6 +90,39 @@ def init_params(key, cfg: ArchConfig) -> Params:
     return params
 
 
+# Leaves the programs read in float32, by name: norm scales and biases, the
+# Mamba decay and step parameters, the xLSTM gate biases, sLSTM's recurrent
+# gates, and the token embedding (its rows are gathered, then cast).  Every
+# other leaf reaches the arithmetic only through a cast to
+# ``layers.COMPUTE_DTYPE``: the matrices ``layers.matmul`` and the MoE/MLA
+# einsums read, the head, the attention and conv biases.
+# tests/test_serving_weights.py holds the rule to the serving programs'
+# jaxprs for every registered architecture.
+F32_LEAVES = frozenset(
+    {"scale", "bias", "a_log", "dt_bias", "if_bias", "gate_bias", "r_gates", "embed"}
+)
+
+
+def read_through_cast(path) -> bool:
+    """Whether the leaf at ``path`` (a ``tree_flatten_with_path`` key path)
+    is read only through a cast to ``layers.COMPUTE_DTYPE``."""
+    return str(getattr(path[-1], "key", path[-1])) not in F32_LEAVES
+
+
+def serving_params(params: Params, cfg: ArchConfig) -> Params:
+    """``params`` with every leaf the programs read only through a cast held
+    in that dtype, cast leaf by leaf; the rest as given.  Same operands for
+    the same arithmetic, without the programs casting the weights on every
+    call.  Where ``cfg.dtype`` is not the compute dtype the casts do not all
+    land on ``cfg.dtype``, and the tree is returned as it is."""
+    if jnp.dtype(cfg.dtype) != jnp.dtype(layers.COMPUTE_DTYPE):
+        return params
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: jnp.asarray(a).astype(cfg.dtype) if read_through_cast(path) else a,
+        params,
+    )
+
+
 def abstract_params(cfg: ArchConfig) -> Params:
     """ShapeDtypeStruct pytree — no allocation (dry-run path)."""
     return jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))

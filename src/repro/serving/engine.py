@@ -124,6 +124,11 @@ class StagePrograms:
     then served from the executable cache.  The cached-decode plane adds a
     per-stage prefill (cache-building), slot-write (scatter into the
     replica's resident store), and cached one-token decode program.
+
+    The programs are handed ``weights``, the serving copy of ``params``
+    (``model.serving_params``): every leaf they read only through a cast to
+    the compute dtype is held in it, once, so no call casts the weights
+    again.  Setting ``params`` makes the copy anew.
     """
 
     def __init__(self, params: Any, cfg: ArchConfig):
@@ -140,27 +145,47 @@ class StagePrograms:
         self._paged_write = {}
         self._block_copy = {}
 
+    @property
+    def params(self) -> Any:
+        """The parameter tree as given."""
+        return self._params
+
+    @params.setter
+    def params(self, params: Any) -> None:
+        self._params = params
+        self.weights = None  # free the old copy before making the new one
+        self.weights = model_lib.serving_params(params, self.cfg)
+        dtype = jnp.dtype(self.cfg.dtype)
+        held = kept = 0
+        for a in jax.tree.leaves(self.weights):
+            if a.dtype == dtype:
+                held += a.nbytes
+            else:
+                kept += a.nbytes
+        # bytes the programs read in the compute dtype, and bytes in any other
+        self.weight_bytes = {"dtype": dtype.name, "held": held, "kept": kept}
+
     def embed(self, tokens: jnp.ndarray) -> jnp.ndarray:
-        return self._embed(self.params, tokens)
+        return self._embed(self.weights, tokens)
 
     def run_stage(self, stage_idx: int, x: jnp.ndarray) -> jnp.ndarray:
         """Forward hidden states through stage ``stage_idx`` (1-indexed)."""
         if stage_idx not in self._stage:
             self._stage[stage_idx] = steps.make_stage_forward(self.cfg, stage_idx)
-        return self._stage[stage_idx](self.params, x)
+        return self._stage[stage_idx](self.weights, x)
 
     def stage_prefill(self, stage_idx: int, x: jnp.ndarray, max_len: int):
         """(x_out, stage caches [n_periods, B, max_len, ...]) for one stage."""
         key = (stage_idx, max_len)
         if key not in self._prefill:
             self._prefill[key] = steps.make_stage_prefill(self.cfg, stage_idx, max_len)
-        return self._prefill[key](self.params, x)
+        return self._prefill[key](self.weights, x)
 
     def stage_decode(self, stage_idx: int, x, slot_caches, slots):
         """One cached token per row against the replica's (donated) store."""
         if stage_idx not in self._decode:
             self._decode[stage_idx] = steps.make_stage_decode(self.cfg, stage_idx)
-        return self._decode[stage_idx](self.params, x, slot_caches, slots)
+        return self._decode[stage_idx](self.weights, x, slot_caches, slots)
 
     def slot_write(self, stage_idx: int, slot_caches, new_caches, slots):
         if stage_idx not in self._slot_write:
@@ -192,7 +217,7 @@ class StagePrograms:
             self._paged_decode[key] = steps.make_paged_stage_decode(
                 self.cfg, stage_idx, seq_len
             )
-        return self._paged_decode[key](self.params, x, pool, state, tables, slots)
+        return self._paged_decode[key](self.weights, x, pool, state, tables, slots)
 
     def block_copy(self, stage_idx, pool, src, dst):
         if stage_idx not in self._block_copy:
@@ -203,11 +228,11 @@ class StagePrograms:
         """(confidence, token) of the exit branch after stage ``stage_idx``."""
         if stage_idx not in self._exit:
             self._exit[stage_idx] = steps.make_exit_head_step(self.cfg, stage_idx)
-        return self._exit[stage_idx](self.params, x_last)
+        return self._exit[stage_idx](self.weights, x_last)
 
     def final_head(self, x_last: jnp.ndarray):
         """(confidence, token) of the final head — fused, no [B, vocab] logits."""
-        return self._final(self.params, x_last)
+        return self._final(self.weights, x_last)
 
 
 # ---------------------------------------------------------------------------
@@ -248,6 +273,9 @@ class ServeStats:
     # the host-span record of the call (repro.obs.host): per-span count,
     # total and self seconds, stage batches, compiles by span
     host: dict | None = None
+    # StagePrograms.weight_bytes: the serving weights' bytes held in the
+    # compute dtype and kept as given
+    weights: dict | None = None
 
     def summary(self) -> dict:
         d = np.asarray(self.delays)
@@ -320,6 +348,8 @@ class ServeStats:
         out = {"summary": self.summary()}
         if self.host is not None:
             out["host"] = self.host
+        if self.weights is not None:
+            out["weights"] = self.weights
         if self.trace is not None:
             from repro.obs.attribution import decompose
 
@@ -646,6 +676,7 @@ class CollaborativeEngine:
             stream = build_stream(telemetry, tracer, metrics)
 
             stats = ServeStats()
+            stats.weights = dict(programs.weight_bytes)
             stats.trace = tracer
             stats.metrics = metrics
             # one precomputed CDF serves every routing sample (shared with the

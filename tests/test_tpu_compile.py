@@ -17,6 +17,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import model as model_lib
+from repro.serving import steps
+from test_serving_weights import f32_convert_shapes
 
 CFG = get_config("stablelm-1.6b")
 
@@ -53,7 +56,7 @@ def _compile(fn, one_chip, *shapes):
 
 @pytest.mark.parametrize("B", [8, 200])
 def test_exit_confidence_compiles(one_chip, pallas, B):
-    # the serving path hands the head over as the f32 master weight
+    # the monolithic steps hand the head over as the f32 master weight
     _compile(
         ops.exit_confidence,
         one_chip,
@@ -90,3 +93,40 @@ def test_paged_decode_attention_compiles(one_chip, pallas):
         ((B, n_logical), jnp.int32),
         ((B,), jnp.int32),
     )
+
+
+def test_stage_programs_cast_no_weight(one_chip, pallas):
+    """From the serving copy, stage decode and the exit head compile with no
+    cast of a weight; from the f32 tree, with a cast of every matrix."""
+    B, max_len = 8, 400
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    params = model_lib.abstract_params(CFG)
+    weights = jax.eval_shape(lambda p: model_lib.serving_params(p, CFG), params)
+    x = jax.ShapeDtypeStruct((B, 1, CFG.d_model), CFG.dtype, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    store = on_chip(jax.eval_shape(
+        lambda: model_lib.init_stage_slot_caches(CFG, 1, 2 * B + 1, max_len)
+    ))
+    matrices = {
+        leaf.shape
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            {"stage": params["stages"][0], "lm_head": params["lm_head"]}
+        )[0]
+        if model_lib.read_through_cast(path)
+    }
+
+    def cast(tree):
+        tree = on_chip(tree)
+        decode = steps.make_stage_decode(CFG, 1).lower(tree, x, store, rows)
+        head = steps.make_exit_head_step(CFG, CFG.exit_stages[0]).lower(tree, x)
+        return set().union(
+            *(f32_convert_shapes(p.compile().as_text()) for p in (decode, head))
+        ) & matrices
+
+    assert cast(params) == matrices
+    assert cast(weights) == set()
