@@ -11,11 +11,13 @@ rounds.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import os
 import shutil
 import sys
 from time import perf_counter
+from typing import Any
 
 import numpy as np
 
@@ -241,12 +243,39 @@ def control_numbers(cell, conf, params, chosen, thresholds, precision: str) -> d
     return check.control_numbers(chosen, ref, low, conf, thresholds, cell.limits)
 
 
+@dataclasses.dataclass
+class Window:
+    """What set-up and the measured window leave for the comparison with the
+    reference: the result line so far, the finished requests (``check.Served``
+    by (round, rid)) with their total lengths, and what the reference needs
+    to make the weights again.  The program's state is gone by then."""
+
+    result: dict
+    conf: dict  # the configuration as run, at the widths the window ran
+    cfg: Any
+    abstract: Any
+    thresholds: np.ndarray
+    by_key: dict
+    lengths: dict
+    failed: int
+    attempted: int
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, t_start: float,
              require_tpu: bool = True, reduced: dict | None = None, wrap_programs=None) -> dict:
     """One run; returns the result line's object.  For tests on the CPU:
     ``reduced`` runs ``cfg.reduced(**reduced)`` instead of the published
     widths, and ``wrap_programs(programs, cfg)`` may replace stage
     programs to plant a fault."""
+    window = measure(cell, seed, seconds, trace, t_start=t_start, require_tpu=require_tpu,
+                     reduced=reduced, wrap_programs=wrap_programs)
+    return judge(cell, window, seed)
+
+
+def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, t_start: float,
+            require_tpu: bool = True, reduced: dict | None = None, wrap_programs=None) -> Window:
+    """Set-up, the measured window and its readers (``run_cell``'s
+    arguments); frees the program's state before it returns."""
     import jax
 
     from benchlib import weights
@@ -336,38 +365,35 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, t_start
             k: {"value": v, "unit": units[k]} for k, v in e2e.items() if k in units and v is not None
         }
 
-    # ---- correctness: the reference, once the program's state is gone ----
     by_key, lengths, failed, attempted = collect_served(served, recorder, cfg, traffic)
     del engine, params, recorder, served
     gc.collect()
+    conf = cell.config if reduced is None else spec.family(cell.config).cpu_conf(cell.config, cfg)
+    return Window(result=result, conf=conf, cfg=cfg, abstract=abstract, thresholds=thresholds,
+                  by_key=by_key, lengths=lengths, failed=failed, attempted=attempted)
+
+
+def judge(cell: spec.Cell, window: Window, seed: int) -> dict:
+    """The comparison with the reference, once the program's state is gone:
+    completes the result line with ``correct`` and the numbers compared,
+    each beside its limit."""
+    from benchlib import weights
+
+    traffic = cell.traffic
     t_ref = perf_counter()
-    keys = check.sample(sorted(by_key), lengths, int(traffic["check_requests"]), seed)
+    by_key = window.by_key
+    keys = check.sample(sorted(by_key), window.lengths, int(traffic["check_requests"]), seed)
     chosen = [by_key[k] for k in keys]
-    conf = cell.config if reduced is None else reduced_conf(cell.config, cfg)
-    ref_params = weights.make_params(cfg, seed, abstract)
-    numbers = reference_numbers(cell, conf, ref_params, chosen, thresholds)
+    ref_params = weights.make_params(window.cfg, seed, window.abstract)
+    numbers = reference_numbers(cell, window.conf, ref_params, chosen, window.thresholds)
     del ref_params
-    correct = check.verdict(numbers, cell.limits, failed)
+    correct = check.verdict(numbers, cell.limits, window.failed)
     log(f"reference: {len(chosen)} requests, {sum(len(r.tokens) for r in chosen)} served tokens, "
         f"{perf_counter() - t_ref:.1f} s")
-    result.update(correct=correct, attempted=attempted, failed=failed)
+    result = window.result
+    result.update(correct=correct, attempted=window.attempted, failed=window.failed)
     result["check"] = {k: {"value": numbers[k], "limit": float(cell.limits[k])} for k in check.NUMBERS}
     for k in check.NUMBERS:
         log(f"check {k}: {numbers[k]!r} limit {float(cell.limits[k])!r}")
-    log(f"check failed_requests: {failed} limit 0")
+    log(f"check failed_requests: {window.failed} limit 0")
     return result
-
-
-def reduced_conf(conf: dict, cfg) -> dict:
-    """The configuration file with the widths of a ``.reduced()`` config
-    (CPU tests only)."""
-    out = dict(conf)
-    out.update(
-        hidden_size=cfg.d_model,
-        num_hidden_layers=cfg.num_layers,
-        num_attention_heads=cfg.num_heads,
-        num_key_value_heads=cfg.num_kv_heads,
-        intermediate_size=cfg.d_ff,
-        vocab_size=cfg.vocab_size,
-    )
-    return out

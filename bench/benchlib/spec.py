@@ -1,13 +1,16 @@
 """What a cell is: its entry in BENCHMARK.json and the data files it names.
 
 Everything here is found by name: a workload names its configuration and
-its traffic mix, the configuration names its file and its reference, and
-each metric is a reader under ``bench/metrics/<name>.py``.  A new cell is
-new files and new entries, never an edit here.
+its traffic mix, the configuration names its file, and the file names its
+family (``bench/families/<family>.py``) and its reference
+(``bench/references/<name>.py``); each metric is a reader under
+``bench/metrics/<name>.py``.  A new cell, or a new architecture, is new
+files and new entries, never an edit here.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -15,6 +18,7 @@ from typing import Any
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
+FAMILIES_DIR = BENCH_DIR / "families"
 
 
 def load_json(path: Path) -> Any:
@@ -23,7 +27,8 @@ def load_json(path: Path) -> Any:
 
 
 def load_module(path: Path, name: str):
-    """Import a reader or reference from its file (names may hold dots)."""
+    """Import a reader, reference or family from its file (names may hold
+    dots)."""
     spec = importlib.util.spec_from_file_location(f"bench_{name.replace('.', '_')}", path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
@@ -33,11 +38,24 @@ def load_module(path: Path, name: str):
 
 
 def load_config(path: Path) -> dict:
-    """A configuration file as run: its published keys, with the value of
-    each of its ``departures`` (where the program differs from the
-    published model, and the reference follows the program) in place."""
+    """A configuration file as run.
+
+    The file keeps each published key at its published value.  Each entry
+    of its ``reduced`` (a size cut to fit the chip) and of its
+    ``departures`` (where the program differs from the published model,
+    and the reference follows the program) states the ``run`` value and
+    ``why``; here the run value is put in place, and the published one is
+    kept in the entry, beside its ``why``."""
     conf = load_json(path)
-    return dict(conf, **{k: d["run"] for k, d in conf.get("departures", {}).items()})
+    out = dict(conf)
+    for group in ("reduced", "departures"):
+        entries = conf.get(group, {})
+        missing = sorted(set(entries) - set(conf))
+        if missing:
+            raise KeyError(f"{path}: {group} names {missing}, which the file does not publish")
+        out[group] = {k: dict(e, published=conf[k]) for k, e in entries.items()}
+        out.update({k: e["run"] for k, e in entries.items()})
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,36 +96,29 @@ def load_cell(name: str, benchmark: dict | None = None) -> Cell:
     )
 
 
+def family(conf: dict):
+    """The family module that a configuration file names under ``family``:
+    ``bench/families/<family>.py``, loaded once per file."""
+    name = conf.get("family")
+    if name is None:
+        raise ValueError(
+            f"configuration {conf.get('name')!r} names no family: give it \"family\", "
+            f"the name of a module under {FAMILIES_DIR}")
+    path = FAMILIES_DIR / f"{name}.py"
+    try:
+        return _family_module(path)
+    except FileNotFoundError:
+        known = sorted(p.stem for p in FAMILIES_DIR.glob("*.py"))
+        raise ValueError(
+            f"configuration {conf.get('name')!r} names family {name!r}, which has no "
+            f"module {path.name} under {FAMILIES_DIR}; known: {known}") from None
+
+
+@functools.cache
+def _family_module(path: Path):
+    return load_module(path, f"family_{path.stem}")
+
+
 def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for a configuration file.
-
-    Widths come from the published keys; the ``serve`` group says how the
-    family maps onto the program's blocks and where the exits sit.
-    """
-    import jax.numpy as jnp
-
-    from repro.configs.base import ArchConfig
-
-    s = conf["serve"]
-    d, heads = conf["hidden_size"], conf["num_attention_heads"]
-    return ArchConfig(
-        name=conf["name"],
-        family="dense",
-        num_layers=conf["num_hidden_layers"],
-        d_model=d,
-        num_heads=heads,
-        num_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"],
-        head_dim=d // heads,
-        norm=s["norm"],
-        act=conf["hidden_act"],
-        ffn=s["ffn"],
-        qkv_bias=s["qkv_bias"],
-        rope_theta=float(conf["rope_theta"]),
-        period=tuple(s["period"]),
-        num_stages=s["num_stages"],
-        exit_stages=tuple(s["exit_stages"]),
-        dtype=getattr(jnp, s["compute_dtype"]),
-    )
-
+    """The program's ``ArchConfig`` for a configuration file, by its family."""
+    return family(conf).arch_config(conf)

@@ -44,7 +44,7 @@ def readings(cell, seeds, control_seeds, *, require_tpu=True, reduced=None, emit
     cfg = spec.arch_config(cell.config)
     if reduced is not None:
         cfg = cfg.reduced(**reduced)
-    conf = cell.config if reduced is None else C.reduced_conf(cell.config, cfg)
+    conf = cell.config if reduced is None else spec.family(cell.config).cpu_conf(cell.config, cfg)
     abstract = weights.abstract_tree(cfg)
     engine = recorder = None
     rows = []

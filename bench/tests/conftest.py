@@ -10,6 +10,12 @@ for p in (str(BENCH.parent / "src"), str(BENCH)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+from benchlib import spec  # noqa: E402
+
+# every cell of BENCHMARK.json: the tests that run a cell run each of them,
+# at the CPU widths its configuration file gives under "cpu"
+WORKLOADS = [w["name"] for w in spec.load_json(spec.ROOT / "BENCHMARK.json")["workloads"]]
+
 
 @pytest.fixture
 def no_compile_cache(monkeypatch):
@@ -28,15 +34,12 @@ SMALL_TRAFFIC = {
     "check_requests": 4,
     "batch_size": 4,
 }
-REDUCED = {"stablelm-chat": {}, "internlm2-chat": {"num_kv_heads": 2}}
 
 
 def small_cell(name, gen_len=3):
     """The cell ``name`` of BENCHMARK.json with ``SMALL_TRAFFIC``: one short
     prompt length, batches of up to 4, ``gen_len`` tokens, eight slots."""
     import dataclasses
-
-    from benchlib import spec
 
     cell = spec.load_cell(name)
     traffic = dict(cell.traffic, **SMALL_TRAFFIC, num_slots=8)
@@ -47,15 +50,17 @@ def small_cell(name, gen_len=3):
 @pytest.fixture
 def small_run(no_compile_cache):
     """``run(cell_name, trace=False, wrap_programs=None, seed=..., gen_len=3)``: one run
-    of the harness at ``.reduced()`` widths on the CPU, chip check skipped."""
+    of the harness at ``.reduced()`` widths (the configuration's ``cpu``) on
+    the CPU, chip check skipped."""
     from time import perf_counter
 
     from benchlib import cell as cell_lib
 
     def run(name, trace=False, wrap_programs=None, seed=2**33 + 5, seconds=0.5, gen_len=3):
+        cell = small_cell(name, gen_len)
         return cell_lib.run_cell(
-            small_cell(name, gen_len), seed, seconds, trace, t_start=perf_counter(), require_tpu=False,
-            reduced=REDUCED[name], wrap_programs=wrap_programs,
+            cell, seed, seconds, trace, t_start=perf_counter(), require_tpu=False,
+            reduced=cell.config["cpu"], wrap_programs=wrap_programs,
         )
 
     return run

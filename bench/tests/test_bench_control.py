@@ -9,10 +9,10 @@ import pytest
 
 from benchlib import check
 
-from conftest import BENCH, REDUCED, small_cell
+from conftest import BENCH, WORKLOADS, small_cell
 
 
-@pytest.mark.parametrize("name", ["stablelm-chat", "internlm2-chat"])
+@pytest.mark.parametrize("name", WORKLOADS)
 def test_control_fails_where_the_program_passes(name, no_compile_cache):
     sys.path.insert(0, str(BENCH))
     import readings
@@ -20,7 +20,7 @@ def test_control_fails_where_the_program_passes(name, no_compile_cache):
     cell = small_cell(name)
     cell = dataclasses.replace(cell, traffic=dict(cell.traffic, check_requests=32))  # four rounds
     rows = readings.readings(cell, [11, 12, 13], {11, 12, 13}, require_tpu=False,
-                             reduced=REDUCED[name], emit=lambda line: None)
+                             reduced=cell.config["cpu"], emit=lambda line: None)
     for row in rows:
         assert row["failed"] == 0
         assert check.verdict(row["program"], cell.limits, 0), row

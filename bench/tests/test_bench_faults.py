@@ -13,6 +13,8 @@ import pytest
 
 from benchlib import check
 
+from conftest import WORKLOADS
+
 
 def stale_cache(programs, cfg):
     """Decode steps return the replica's store as it was: the token's keys
@@ -53,8 +55,7 @@ def altered_token(programs, cfg):
 
 
 @pytest.mark.parametrize("name,fault", [
-    ("stablelm-chat", stale_cache), ("stablelm-chat", half_batch), ("stablelm-chat", altered_token),
-    ("internlm2-chat", stale_cache), ("internlm2-chat", altered_token),
+    (name, fault) for name in WORKLOADS for fault in (stale_cache, half_batch, altered_token)
 ], ids=lambda v: getattr(v, "__name__", v))
 def test_fault_fails_the_check(name, fault, small_run):
     # eight tokens: a stale cache then misses seven positions, not one

@@ -1,5 +1,6 @@
 """The benchmark's float32 reference against the program's own prefill at
-``.reduced()`` widths, for both configurations.
+``.reduced()`` widths (each configuration's ``cpu``), for every
+configuration of BENCHMARK.json.
 
 With the program's matrix products switched to float32 for the test, the
 two compute the same equations in the same precision and must agree to
@@ -13,22 +14,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchlib import cell as cell_lib, spec, weights
+from benchlib import spec, weights
 
-CASES = {
-    "stablelm-1.6b": dict(num_kv_heads=4),
-    "internlm2-1.8b": dict(num_kv_heads=2),  # keep grouped heads (group 2)
-}
+BENCHMARK = spec.load_json(spec.ROOT / "BENCHMARK.json")
+FILES = {c["name"]: spec.ROOT / c["file"] for c in BENCHMARK["configs"]}
 # float32 against float32: the log-confidences and the logits agree to a
 # few hundred float32 ulps of the values involved (O(10))
 TIGHT = 2e-4
 
 
 def setup(name, dtype):
-    conf = spec.load_config(spec.BENCH_DIR / "configs" / f"{name}.json")
-    cfg = spec.arch_config(conf).reduced(**CASES[name])
+    conf = spec.load_config(FILES[name])
+    cfg = spec.arch_config(conf).reduced(**conf["cpu"])
     cfg = dataclasses.replace(cfg, dtype=dtype)
-    conf = cell_lib.reduced_conf(conf, cfg)
+    conf = spec.family(conf).cpu_conf(conf, cfg)
     params = weights.make_params(cfg, 2**32 + 7)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (3, 24)).astype(np.int32)
     return conf, cfg, params, tokens
@@ -75,7 +74,7 @@ def gaps(prog, ref, conf):
     return worst, same
 
 
-@pytest.mark.parametrize("name", list(CASES))
+@pytest.mark.parametrize("name", list(FILES))
 def test_reference_matches_float32_program(name, monkeypatch):
     conf, cfg, params, tokens = setup(name, jnp.float32)
     prog = program_prefill(params, cfg, tokens, True, monkeypatch)
@@ -85,7 +84,7 @@ def test_reference_matches_float32_program(name, monkeypatch):
     assert worst_bf16 > 10 * TIGHT, worst_bf16
 
 
-@pytest.mark.parametrize("name", list(CASES))
+@pytest.mark.parametrize("name", list(FILES))
 def test_served_precision_inside_the_cell_limits(name, monkeypatch):
     conf, cfg, params, tokens = setup(name, jnp.bfloat16)
     prog = program_prefill(params, cfg, tokens, False, monkeypatch)
@@ -98,10 +97,10 @@ def test_served_precision_inside_the_cell_limits(name, monkeypatch):
     ref = mod.readings(params, conf, tokens, np.full((N, 1), L - 1, np.int32), gather, rows=2)
     conf_gap, _ = gaps(prog, ref, conf)
     token_gap = max(float(np.max(r["lmax"] - r["picked"])) for r in ref.values())
-    cell = {"stablelm-1.6b": "stablelm-chat", "internlm2-1.8b": "internlm2-chat"}[name]
-    limits = spec.load_json(spec.BENCH_DIR / "limits" / f"{cell}.json")
-    assert conf_gap <= limits["conf_gap"], conf_gap
-    assert token_gap <= limits["token_gap"], token_gap
+    for cell in [w["name"] for w in BENCHMARK["workloads"] if w["config"] == name]:
+        limits = spec.load_json(spec.BENCH_DIR / "limits" / f"{cell}.json")
+        assert conf_gap <= limits["conf_gap"], (cell, conf_gap)
+        assert token_gap <= limits["token_gap"], (cell, token_gap)
 
 
 def test_departure_is_what_runs(monkeypatch):

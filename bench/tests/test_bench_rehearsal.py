@@ -10,11 +10,13 @@ import pytest
 
 from benchlib import check, spec
 
+from conftest import WORKLOADS
+
 
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "check"]
 
 
-@pytest.mark.parametrize("name", ["stablelm-chat", "internlm2-chat"])
+@pytest.mark.parametrize("name", WORKLOADS)
 def test_untraced_run_line(name, small_run):
     res = small_run(name)
     assert list(res) == KEYS  # the compared numbers come last
